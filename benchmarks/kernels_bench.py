@@ -1,9 +1,11 @@
-"""Kernel microbench: analytic roofline terms + CPU-oracle agreement.
+"""Kernel microbench: analytic roofline terms + CPU-oracle timings.
 
-No TPU is attached, so wall-clock numbers here are the XLA-oracle CPU times
-(reported for relative comparison only). The meaningful kernel outputs are
-the analytic per-call FLOPs / HBM bytes / VMEM working set that the
-BlockSpec tiling commits to — these feed the §Perf napkin math.
+The timings here are of the pure-jnp oracles in ``kernels/ref.py`` on
+whatever backend JAX picks, usually the host CPU: they compare oracle
+formulations (e.g. sequential vs chunked SSD) and are not device kernel
+times. The meaningful kernel outputs are the analytic per-call FLOPs / HBM
+bytes / VMEM working set that the BlockSpec tiling commits to. Compiled
+kernels at real widths run on the chip in ``chip_smoke.py``.
 """
 from __future__ import annotations
 
@@ -45,7 +47,7 @@ def run() -> dict:
         "shape": f"b{b} s{s} n{n} kv{kv} h{h} w128",
         "analytic_flops": fa.flops(b, s, s, n, h, causal=True),
         "vmem_bytes_per_step": fa.vmem_bytes(128, 128, h),
-        "cpu_oracle_ms": t_ref * 1e3,
+        "oracle_ms": t_ref * 1e3,
     })
 
     # decode attention: 32k cache read
@@ -59,7 +61,7 @@ def run() -> dict:
         "kernel": "decode_attention",
         "shape": f"b{b} skv{s_kv} n{n} kv{kv} h{h}",
         "analytic_hbm_bytes": da.hbm_bytes(b, s_kv, kv, h),
-        "cpu_oracle_ms": t_ref * 1e3,
+        "oracle_ms": t_ref * 1e3,
     })
 
     # ssd: mamba2-130m-class block
@@ -77,19 +79,20 @@ def run() -> dict:
         "kernel": "ssd",
         "shape": f"b{b} s1024 h{hh} p{p} n{nn} chunk{ch}",
         "analytic_flops": ssd_mod.flops(b, 1024, hh, p, nn, ch),
-        "cpu_sequential_ms": t_seq * 1e3,
-        "cpu_chunked_ms": t_chunk * 1e3,
+        "oracle_sequential_ms": t_seq * 1e3,
+        "oracle_chunked_ms": t_chunk * 1e3,
         "chunked_speedup": t_seq / t_chunk,
     })
 
-    out = {"rows": rows}
+    out = {"backend": jax.default_backend(), "rows": rows}
     save_artifact("kernels_bench", out)
     return out
 
 
 def main() -> None:
     out = run()
-    print("kernel microbench (CPU oracle timings; analytic TPU terms):")
+    print(f"kernel microbench (oracle timings on {jax.default_backend()}, "
+          "not device kernel times; analytic TPU terms):")
     for r in out["rows"]:
         print("  " + ", ".join(f"{k}={v}" for k, v in r.items()))
 

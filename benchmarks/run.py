@@ -113,6 +113,9 @@ def main() -> None:
         except OSError as e:
             ap.error(f"--json path not writable: {e}")
     import importlib
+
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     failures = []
     payloads: dict[str, object] = {}
     wall_s: dict[str, float] = {}

@@ -13,10 +13,7 @@ import sys
 
 sys.path.insert(0, "src")
 
-import numpy as np
-
-from repro.launch.serve import build_handle
-from repro.serving import RequestQueue, ServingEngine, VirtualAccelerator
+from repro.launch.serve import TOY, serve
 
 
 def main() -> None:
@@ -26,44 +23,8 @@ def main() -> None:
                     help="double every FPS target to show frame drop + "
                          "supernet switching under load")
     args = ap.parse_args()
-
-    accs = [
-        VirtualAccelerator("big0", speed=1.0, power=1.0),
-        VirtualAccelerator("small0", speed=0.45, power=0.4),
-        VirtualAccelerator("small1", speed=0.45, power=0.4),
-    ]
-    engine = ServingEngine(accs, adaptivity=True, frame_drop=True,
-                           supernet_switch=True)
-
-    det = build_handle("gemma-2b", "detector", layers=2)
-    verif = build_handle("qwen1.5-4b", "verifier", layers=2)
-    ctx = build_handle("gemma2-2b", "context", layers=4)
-    ctx_v1 = build_handle("gemma2-2b", "context@v1", layers=2)
-    ctx.supernet = ("context@v1",)
-    kws = build_handle("mamba2-130m", "kws", layers=2)
-
-    calib32 = np.zeros((1, 32), np.int32)
-    calib16 = np.zeros((1, 16), np.int32)
-    for h in (det, verif, ctx, ctx_v1):
-        engine.register(h, calib32)
-    engine.register(kws, calib16)
-
-    mult = 2.0 if args.overload else 1.0
-    q = RequestQueue(clock=lambda: 0.0)
-    q.add_stream("detector", fps=8 * mult, batch=1, seq=32, vocab=128)
-    q.add_stream("verifier", fps=8 * mult, batch=1, seq=32, vocab=128,
-                 depends_on="detector", trigger_prob=0.5)
-    q.add_stream("context", fps=4 * mult, batch=1, seq=32, vocab=128)
-    q.add_stream("kws", fps=12 * mult, batch=1, seq=16, vocab=128)
-
-    report = engine.run(q, duration_s=args.duration)
-    print("[serve_rtmm]", report.summary())
-    for name, st in sorted(report.per_model.items()):
-        print(f"[serve_rtmm]   {name:>12s} frames={st['frames']:4d} "
-              f"violated={st['violated']:4d} energy={st['energy']:.3f}")
-    print(f"[serve_rtmm] adapted (alpha, beta) = "
-          f"({report.alpha:.2f}, {report.beta:.2f}); "
-          f"aborted={engine.aborted}")
+    serve(TOY, duration_s=args.duration,
+          fps_scale=2.0 if args.overload else 1.0)
 
 
 if __name__ == "__main__":

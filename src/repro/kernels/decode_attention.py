@@ -12,6 +12,10 @@ All query heads of one KV head are processed together ([group, H] q tile):
 with GQA this turns the per-tile work into a [group, H] x [H, BK] MXU matmul
 instead of a bandwidth-starved GEMV, and each KV byte fetched from HBM is
 reused ``group`` times — the classic GQA decode win.
+
+The cache is read as [B, S, K*H] (a free reshape): KV head k is lane block
+k, so each tile is (block_k, H) without moving the cache. Mosaic takes that
+tile when H is a multiple of 128 or K == 1.
 """
 from __future__ import annotations
 
@@ -22,10 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-# jax<0.5 ships the TPU params under the old TPUCompilerParams name
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
 
 Array = jax.Array
 
@@ -63,9 +63,9 @@ def _decode_kernel(
 
     @pl.when(run)
     def _body():
-        q = q_ref[0, 0, :, :].astype(jnp.float32)           # [G, H]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)           # [BK, H]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)           # [BK, H]
+        q = q_ref[...].astype(jnp.float32)                  # [G, H]
+        k = k_ref[...].astype(jnp.float32)                  # [BK, H]
+        v = v_ref[...].astype(jnp.float32)                  # [BK, H]
         logits = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale      # [G, BK]
@@ -76,13 +76,13 @@ def _decode_kernel(
         if window is not None:
             mask = mask & (ki > p - window)
         logits = jnp.where(mask, logits, NEG_INF)
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1))
+        m_prev = m_scr[...]                                 # [G, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
         m_safe = jnp.where(m_new == -jnp.inf, 0.0, m_new)
-        pexp = jnp.where(mask, jnp.exp(logits - m_safe[:, None]), 0.0)
+        pexp = jnp.where(mask, jnp.exp(logits - m_safe), 0.0)
         alpha = jnp.where(m_prev == -jnp.inf, 0.0, jnp.exp(m_prev - m_safe))
-        l_scr[...] = alpha * l_scr[...] + jnp.sum(pexp, axis=-1)
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
+        l_scr[...] = alpha * l_scr[...] + jnp.sum(pexp, axis=-1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
             pexp, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_scr[...] = m_new
@@ -91,7 +91,7 @@ def _decode_kernel(
     def _finalize():
         l = l_scr[...]
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0, :, :] = (acc_scr[...] / l_safe[:, None]).astype(o_ref.dtype)
+        o_ref[...] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -131,26 +131,27 @@ def decode_attention(
             num_scalar_prefetch=1,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, 1, group, h),
+                pl.BlockSpec((None, None, group, h),
                              lambda bb, kk, kb, pos_ref: (bb, kk, 0, 0)),
-                pl.BlockSpec((1, block_k, 1, h),
-                             lambda bb, kk, kb, pos_ref: (bb, kb, kk, 0)),
-                pl.BlockSpec((1, block_k, 1, h),
-                             lambda bb, kk, kb, pos_ref: (bb, kb, kk, 0)),
+                pl.BlockSpec((None, block_k, h),
+                             lambda bb, kk, kb, pos_ref: (bb, kb, kk)),
+                pl.BlockSpec((None, block_k, h),
+                             lambda bb, kk, kb, pos_ref: (bb, kb, kk)),
             ],
-            out_specs=pl.BlockSpec((1, 1, group, h),
+            out_specs=pl.BlockSpec((None, None, group, h),
                                    lambda bb, kk, kb, pos_ref: (bb, kk, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((group,), jnp.float32),
-                pltpu.VMEM((group,), jnp.float32),
+                pltpu.VMEM((group, 1), jnp.float32),
+                pltpu.VMEM((group, 1), jnp.float32),
                 pltpu.VMEM((group, h), jnp.float32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, kv, group, h), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(pos.astype(jnp.int32), qg, k_cache, v_cache)
+    )(pos.astype(jnp.int32), qg, k_cache.reshape(b, s, kv * h),
+      v_cache.reshape(b, s, kv * h))
     return out.reshape(b, n, h)
 
 

@@ -3,10 +3,11 @@
 TPU-native blocked attention: the grid walks (batch, q_head, q_block,
 k_block) with the k_block axis innermost — TPU grids execute sequentially,
 so VMEM scratch carries the running softmax statistics (m, l) and the
-output accumulator across k-blocks of one q-block. BlockSpecs tile Q/K/V
-into (block_q x head_dim) / (block_k x head_dim) VMEM-resident tiles; the
-MXU sees [block_q, head_dim] x [head_dim, block_k] matmuls with both dims
-padded to the 128-lane layout by construction.
+output accumulator across k-blocks of one q-block. The wrapper moves heads
+ahead of the sequence ([B, N, S, H]) so every BlockSpec tiles the last two
+dims as (block x head_dim): the tiling Mosaic requires (sublane multiple of
+8, full-extent head_dim). The MXU sees [block_q, head_dim] x
+[head_dim, block_k] matmuls.
 
 GQA is folded into the index maps (query head n reads kv head n * K // N),
 so no jnp.repeat materializes the expanded KV. Causal and sliding-window
@@ -24,10 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-# jax<0.5 ships the TPU params under the old TPUCompilerParams name
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
 
 Array = jax.Array
 
@@ -72,9 +69,9 @@ def _flash_kernel(
 
     @pl.when(run)
     def _body():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)          # [BQ, H]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)          # [BK, H]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)          # [BK, H]
+        q = q_ref[...].astype(jnp.float32)                 # [BQ, H]
+        k = k_ref[...].astype(jnp.float32)                 # [BK, H]
+        v = v_ref[...].astype(jnp.float32)                 # [BK, H]
         logits = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale     # [BQ, BK]
@@ -89,17 +86,17 @@ def _flash_kernel(
             mask = mask & (ki > qi - window)
         logits = jnp.where(mask, logits, NEG_INF)
 
-        m_prev = m_scr[...]                                 # [BQ]
+        m_prev = m_scr[...]                                 # [BQ, 1]
         l_prev = l_scr[...]
-        m_cur = jnp.max(logits, axis=-1)
+        m_cur = jnp.max(logits, axis=-1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
         m_safe = jnp.where(m_new == -jnp.inf, 0.0, m_new)   # all-masked rows
-        p = jnp.exp(logits - m_safe[:, None])
+        p = jnp.exp(logits - m_safe)
         p = jnp.where(mask, p, 0.0)
         alpha = jnp.where(m_prev == -jnp.inf, 0.0,
                           jnp.exp(m_prev - m_safe))
-        l_new = alpha * l_prev + jnp.sum(p, axis=-1)
-        acc = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
+        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+        acc = acc_scr[...] * alpha + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_scr[...] = m_new
@@ -110,7 +107,7 @@ def _flash_kernel(
     def _finalize():
         l = l_scr[...]
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, :, 0, :] = (acc_scr[...] / l_safe[:, None]).astype(o_ref.dtype)
+        o_ref[...] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -146,37 +143,41 @@ def flash_attention(
         _flash_kernel, scale=scale, causal=causal, window=window,
         softcap=softcap, block_q=block_q, block_k=block_k)
 
-    return pl.pallas_call(
+    # head-major layout: the tiled dims (seq, head_dim) go last
+    qh, kh, vh = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
+    out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q, 1, h),
-                         lambda bb, nn, qb, kb: (bb, qb, nn, 0)),
-            pl.BlockSpec((1, block_k, 1, h),
-                         lambda bb, nn, qb, kb: (bb, kb, nn // q_heads_per_kv, 0)),
-            pl.BlockSpec((1, block_k, 1, h),
-                         lambda bb, nn, qb, kb: (bb, kb, nn // q_heads_per_kv, 0)),
+            pl.BlockSpec((None, None, block_q, h),
+                         lambda bb, nn, qb, kb: (bb, nn, qb, 0)),
+            pl.BlockSpec((None, None, block_k, h),
+                         lambda bb, nn, qb, kb: (bb, nn // q_heads_per_kv, kb, 0)),
+            pl.BlockSpec((None, None, block_k, h),
+                         lambda bb, nn, qb, kb: (bb, nn // q_heads_per_kv, kb, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, 1, h),
-                               lambda bb, nn, qb, kb: (bb, qb, nn, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, sq, n, h), q.dtype),
+        out_specs=pl.BlockSpec((None, None, block_q, h),
+                               lambda bb, nn, qb, kb: (bb, nn, qb, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, n, sq, h), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, h), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
-    )(q, k, v)
+    )(qh, kh, vh)
+    return out.transpose(0, 2, 1, 3)
 
 
 def vmem_bytes(block_q: int, block_k: int, head_dim: int,
                dtype_bytes: int = 2) -> int:
     """VMEM working set of one grid step (tiles + scratch), for block tuning."""
     tiles = (block_q + 2 * block_k) * head_dim * dtype_bytes
-    scratch = (2 * block_q + block_q * head_dim) * 4
+    # m and l are [block_q, 1] f32 columns, lane-padded to 128 in VMEM
+    scratch = (2 * block_q * 128 + block_q * head_dim) * 4
     out = block_q * head_dim * dtype_bytes
     return tiles + scratch + out
 

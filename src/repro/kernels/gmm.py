@@ -23,21 +23,24 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax<0.5 ships the TPU params under the old TPUCompilerParams name
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 Array = jax.Array
 
 DEFAULT_BLOCK_T = 128
 DEFAULT_BLOCK_F = 512
 
 
+LANE = 128
+
+
 def _fit_block(dim: int, pref: int) -> int:
-    """Largest divisor of ``dim`` that is <= ``pref``."""
-    b = min(dim, pref)
+    """Lane tile for a dim of ``dim``: the largest multiple of 128 that
+    divides it and is <= ``pref`` (at least 128), or the whole dim when
+    it is not a multiple of 128 (Mosaic takes a full-extent block)."""
+    if dim % LANE:
+        return dim
+    b = max(min(dim, pref) // LANE * LANE, LANE)
     while dim % b:
-        b -= 1
+        b -= LANE
     return b
 
 
@@ -81,7 +84,7 @@ def gmm_padded(
                                    lambda tb, fb, eid: (tb, fb)),
         ),
         out_shape=jax.ShapeDtypeStruct((tp, f), x.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(tile_eid.astype(jnp.int32), x, w)

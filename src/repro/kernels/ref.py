@@ -201,8 +201,11 @@ def gmm(x: Array, w: Array, group_sizes: Array) -> Array:
     """x: [T, D] rows sorted by group; w: [E, D, F]; group_sizes: [E] int32.
     Row t belongs to group g(t) = searchsorted(cumsum(sizes), t, 'right').
     Returns [T, F] with out[t] = x[t] @ w[g(t)]."""
-    t = x.shape[0]
+    t, e = x.shape[0], w.shape[0]
     bounds = jnp.cumsum(group_sizes)
     gid = jnp.searchsorted(bounds, jnp.arange(t), side="right")
-    wt = jnp.take(w, gid, axis=0)                    # [T, D, F]
-    return jnp.einsum("td,tdf->tf", x, wt.astype(x.dtype))
+    # rows spread over a one-hot expert axis instead of gathering w per
+    # row: [T, E, D] stays within memory at real widths, [T, D, F] cannot
+    onehot = (gid[:, None] == jnp.arange(e)[None, :]).astype(x.dtype)
+    xe = x[:, None, :] * onehot[:, :, None]
+    return jnp.einsum("ted,edf->tf", xe, w.astype(x.dtype))

@@ -12,11 +12,16 @@ This trades the sequential length-S scan for S/L sequential steps of dense
 VPU-serial; chunk matmuls hit the MXU).
 
 Kernel layout: grid (batch, head, chunk), chunk innermost/sequential; the
-running [N, P] state lives in VMEM scratch across chunk steps. B/C are
-shared across heads (G=1), so their tiles are indexed by (batch, chunk)
-only; the compiler keeps them resident across the head loop... heads are
-the second grid axis, so B/C tiles revisit — acceptable: N is small (64-128)
-and the x/y tiles dominate VMEM.
+running [N, P] state lives in VMEM scratch across chunk steps. The wrapper
+moves heads ahead of the sequence (x: [B, H, S, P], dt: [B, H, 1, S]) so
+every tile's last two dims are (chunk, P) / (1, chunk): the tiling Mosaic
+requires. The per-head scalars A and D sit whole in SMEM. B/C are shared
+across heads (G=1), so their tiles are indexed by (batch, chunk) only and
+revisit across the head axis — acceptable: N is small (64-128) and the x/y
+tiles dominate VMEM.
+
+The chunk's cumulative log-decay is a masked lane reduction of the dt row,
+so no scan or transpose runs inside the kernel.
 
 All math in fp32 (the recurrence is exp-weighted; bf16 decays drift).
 """
@@ -29,10 +34,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax<0.5 ships the TPU params under the old TPUCompilerParams name
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 Array = jax.Array
 
 
@@ -43,6 +44,7 @@ def _ssd_kernel(
     *,
     chunk: int,
 ):
+    hh = pl.program_id(1)
     cb = pl.program_id(2)
     ncb = pl.num_programs(2)
 
@@ -50,24 +52,31 @@ def _ssd_kernel(
     def _init():
         state_scr[...] = jnp.zeros_like(state_scr)
 
-    x = x_ref[0, :, 0, :].astype(jnp.float32)        # [L, P]
-    dt = dt_ref[0, :, 0].astype(jnp.float32)         # [L]
-    a = a_ref[0].astype(jnp.float32)                 # scalar (this head)
-    bmat = b_ref[0, :, :].astype(jnp.float32)        # [L, N]
-    cmat = c_ref[0, :, :].astype(jnp.float32)        # [L, N]
-    dd = d_ref[0].astype(jnp.float32)                # scalar
+    x = x_ref[...].astype(jnp.float32)               # [L, P]
+    dt_row = dt_ref[...].astype(jnp.float32)         # [1, L]
+    a = a_ref[hh]                                    # scalar (this head)
+    bmat = b_ref[...].astype(jnp.float32)            # [L, N]
+    cmat = c_ref[...].astype(jnp.float32)            # [L, N]
+    dd = d_ref[hh]                                   # scalar
 
-    la = a * dt                                      # [L] log-decays (<= 0)
-    cum = jnp.cumsum(la)                             # inclusive
-
-    # intra-chunk: y_i = sum_{j<=i} exp(cum_i - cum_j) (C_i . B_j) dt_j x_j
-    seg = jnp.exp(cum[:, None] - cum[None, :])       # [L, L]
     ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    seg = jnp.where(ii >= jj, seg, 0.0)
+    causal = ii >= jj
+    la_row = a * dt_row                              # [1, L] log-decays (<= 0)
+    # inclusive cumsum as a column: cum_i = sum_{j<=i} la_j
+    cum = jnp.sum(jnp.where(causal, la_row, 0.0), axis=1, keepdims=True)
+    diag = ii == jj
+    cum_row = jnp.sum(jnp.where(diag, cum, 0.0), axis=0,
+                      keepdims=True)                 # [1, L] (same values)
+    dt = jnp.sum(jnp.where(diag, dt_row, 0.0), axis=1,
+                 keepdims=True)                      # [L, 1]
+    total = jnp.sum(la_row, axis=1, keepdims=True)   # [1, 1] = cum_L
+
+    # intra-chunk: y_i = sum_{j<=i} exp(cum_i - cum_j) (C_i . B_j) dt_j x_j
+    seg = jnp.where(causal, jnp.exp(cum - cum_row), 0.0)          # [L, L]
     m = jax.lax.dot_general(cmat, bmat, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)   # [L, L]
-    m = m * seg * dt[None, :]
+    m = m * seg * dt_row
     y = jax.lax.dot_general(m, x, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)   # [L, P]
 
@@ -75,19 +84,19 @@ def _ssd_kernel(
     state = state_scr[...]                           # [N, P]
     y_in = jax.lax.dot_general(cmat, state, (((1,), (0,)), ((), ())),
                                preferred_element_type=jnp.float32)
-    y = y + jnp.exp(cum)[:, None] * y_in + dd * x
-    y_ref[0, :, 0, :] = y.astype(y_ref.dtype)
+    y = y + jnp.exp(cum) * y_in + dd * x
+    y_ref[...] = y.astype(y_ref.dtype)
 
     # state update: state' = exp(cum_L) state + sum_j exp(cum_L - cum_j) dt_j B_j x_j^T
-    w = jnp.exp(cum[-1] - cum) * dt                  # [L]
-    upd = jax.lax.dot_general(bmat * w[:, None], x,
+    w = jnp.exp(total - cum) * dt                    # [L, 1]
+    upd = jax.lax.dot_general(bmat * w, x,
                               (((0,), (0,)), ((), ())),
                               preferred_element_type=jnp.float32)  # [N, P]
-    state_scr[...] = jnp.exp(cum[-1]) * state + upd
+    state_scr[...] = jnp.exp(total) * state + upd
 
     @pl.when(cb == ncb - 1)
     def _emit_final():
-        fin_ref[0, 0, :, :] = state_scr[...]
+        fin_ref[...] = state_scr[...]
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
@@ -110,32 +119,39 @@ def ssd(
     grid = (b, h, s // chunk)
 
     kernel = functools.partial(_ssd_kernel, chunk=chunk)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
 
+    # head-major layout: the tiled dims (seq, P) / (1, seq) go last
+    xh = x.transpose(0, 2, 1, 3)
+    dth = dt.transpose(0, 2, 1)[:, :, None, :]
     y, fin = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, chunk, 1, p), lambda bb, hh, cc: (bb, cc, hh, 0)),
-            pl.BlockSpec((1, chunk, 1), lambda bb, hh, cc: (bb, cc, hh)),
-            pl.BlockSpec((1,), lambda bb, hh, cc: (hh,)),
-            pl.BlockSpec((1, chunk, n), lambda bb, hh, cc: (bb, cc, 0)),
-            pl.BlockSpec((1, chunk, n), lambda bb, hh, cc: (bb, cc, 0)),
-            pl.BlockSpec((1,), lambda bb, hh, cc: (hh,)),
+            pl.BlockSpec((None, None, chunk, p),
+                         lambda bb, hh, cc: (bb, hh, cc, 0)),
+            pl.BlockSpec((None, None, 1, chunk),
+                         lambda bb, hh, cc: (bb, hh, 0, cc)),
+            smem,
+            pl.BlockSpec((None, chunk, n), lambda bb, hh, cc: (bb, cc, 0)),
+            pl.BlockSpec((None, chunk, n), lambda bb, hh, cc: (bb, cc, 0)),
+            smem,
         ],
         out_specs=[
-            pl.BlockSpec((1, chunk, 1, p), lambda bb, hh, cc: (bb, cc, hh, 0)),
-            pl.BlockSpec((1, 1, n, p), lambda bb, hh, cc: (bb, hh, 0, 0)),
+            pl.BlockSpec((None, None, chunk, p),
+                         lambda bb, hh, cc: (bb, hh, cc, 0)),
+            pl.BlockSpec((None, None, n, p), lambda bb, hh, cc: (bb, hh, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, s, h, p), x.dtype),
+            jax.ShapeDtypeStruct((b, h, s, p), x.dtype),
             jax.ShapeDtypeStruct((b, h, n, p), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(x, dt, A, B, C, D)
-    return y, fin
+    )(xh, dth, A.astype(jnp.float32), B, C, D.astype(jnp.float32))
+    return y.transpose(0, 2, 1, 3), fin
 
 
 def flops(b: int, s: int, h: int, p: int, n: int, chunk: int) -> int:

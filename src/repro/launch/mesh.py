@@ -9,12 +9,7 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
-from jax.sharding import Mesh
-
-try:  # AxisType landed after jax 0.4; Auto is that jax's only behaviour
-    from jax.sharding import AxisType
-except ImportError:
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 from repro.configs import ArchConfig, ShapeCell
 from repro.distributed.sharding import DEFAULT_RULES, adapt_rules_for
@@ -25,8 +20,6 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     'pod' axis of 2 = 512 chips; FSDP state shards over (pod, data)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
                          axis_types=(AxisType.Auto,) * len(axes))
 

@@ -194,7 +194,12 @@ def _apply_shared_attn(params: dict, cfg: ArchConfig, x: Array, x0: Array,
 # ---------------------------------------------------------------------------
 
 
-def init_params(key: Array, cfg: ArchConfig) -> dict:
+def init_params(key: Array, cfg: ArchConfig,
+                weight_dtype=jnp.float32) -> dict:
+    """Seeded parameters, float32 by default. ``weight_dtype`` holds the
+    matmul weights in that dtype as each group is made (the values of
+    ``cast_matmul_weights`` applied afterwards), so a served model never
+    holds all of its float32 weights at once."""
     pat = group_pattern(cfg)
     g = num_groups(cfg)
     keys = jax.random.split(key, 4)
@@ -215,6 +220,7 @@ def init_params(key: Array, cfg: ArchConfig) -> dict:
             "mlp": layers.mlp_init(ks[1], cfg.d_model, cfg.d_ff,
                                    gated=cfg.mlp_gated),
         }
+    cast_matmul_weights(params, weight_dtype)
     gkeys = jax.random.split(keys[3], g)
 
     def one_group(k):
@@ -222,8 +228,40 @@ def init_params(key: Array, cfg: ArchConfig) -> dict:
         return {str(i): _block_init(bkeys[i], cfg, kind)
                 for i, kind in enumerate(pat)}
 
-    groups = [one_group(k) for k in gkeys]
-    params["blocks"] = jax.tree.map(lambda *xs: jnp.stack(xs), *groups)
+    # stack one leaf at a time and release its per-group parts as it goes:
+    # the peak is all groups plus one stacked leaf, not all groups twice
+    cols = []
+    for k in gkeys:
+        leaves, treedef = jax.tree.flatten(
+            cast_matmul_weights(one_group(k), weight_dtype))
+        cols.append(leaves)
+    stacked = []
+    for i in range(len(cols[0])):
+        stacked.append(jnp.stack([c[i] for c in cols]))
+        for c in cols:
+            c[i] = None
+    params["blocks"] = jax.tree.unflatten(treedef, stacked)
+    return params
+
+
+def cast_matmul_weights(params: dict, dtype) -> dict:
+    """Hold every matmul weight (>= 2-D per layer) in ``dtype``, in place.
+
+    The forward casts these weights to the compute dtype itself, so at
+    that dtype the logits do not change, while the weights take half the
+    memory of float32 and need no per-call copy. Norm scales and the SSM
+    per-head vectors stay float32. Leaves are replaced one at a time, so
+    the peak is the params plus one cast leaf, not both copies.
+    """
+    def walk(tree: dict, lead: int) -> None:
+        for k, v in tree.items():
+            if isinstance(v, dict):
+                walk(v, lead)
+            elif v.ndim - lead >= 2:
+                tree[k] = v.astype(dtype)
+
+    for k, v in params.items():
+        walk(v, 1 if k == "blocks" else 0)   # blocks carry a group axis
     return params
 
 

@@ -22,7 +22,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
-from typing import Callable
+from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -150,9 +150,12 @@ def moe_einsum(params: dict, cfg: MoEConfig, x: Array,
 
 
 def moe_gmm(params: dict, cfg: MoEConfig, x: Array,
-            constrain: Constrain = _id, interpret: bool = True
+            constrain: Constrain = _id, interpret: Optional[bool] = None
             ) -> tuple[Array, Array]:
-    """x: [B, S, D] -> ([B, S, D], aux). Sort + Pallas grouped matmul."""
+    """x: [B, S, D] -> ([B, S, D], aux). Sort + Pallas grouped matmul.
+
+    ``interpret=None`` lets ``kernels.ops`` choose: compiled on a TPU, the
+    Pallas interpreter elsewhere."""
     from repro.kernels import ops as kops
 
     b, s, d = x.shape

@@ -34,7 +34,7 @@ import numpy as np
 
 from repro.configs import ArchConfig
 from repro.core.mapscore import MapScoreParams
-from repro.core.uxcost import WindowStats, uxcost
+from repro.core.uxcost import WindowStats, norm_energy, rate_dlv, uxcost
 
 
 # ---------------------------------------------------------------------------
@@ -75,7 +75,6 @@ class RequestQueue:
 
     clock: Callable[[], float]
     streams: dict[str, dict] = field(default_factory=dict)
-    pending: list[ServeRequest] = field(default_factory=list)
     _rid: itertools.count = field(default_factory=itertools.count)
 
     def add_stream(self, model: str, fps: float, batch: int, seq: int,
@@ -117,7 +116,6 @@ class RequestQueue:
                 else:
                     st["next_t"] = st["arrival"].next_after(
                         t, 1.0 / st["fps"], st["rng"])
-        self.pending.extend(out)
         return out
 
     def trigger_dependents(self, parent: str, now: float) -> list[ServeRequest]:
@@ -126,7 +124,6 @@ class RequestQueue:
             if st["depends_on"] == parent and \
                     st["rng"].random() < st["trigger_prob"]:
                 out.append(self._make(name, st, now))
-        self.pending.extend(out)
         return out
 
     def _make(self, name: str, st: dict, t: float) -> ServeRequest:
@@ -162,7 +159,6 @@ class TraceReplayQueue(RequestQueue):
             q = self._times.get(name)
             while q and q[0] <= now:
                 out.append(self._make(name, st, q.popleft()))
-        self.pending.extend(out)
         return out
 
 
@@ -231,6 +227,9 @@ class ServingEngine:
         self.accs = accelerators
         self.models: dict[str, ModelHandle] = {}
         self.lat_table: dict[tuple[str, str], float] = {}  # (model, acc) -> s
+        self.warmup_s: dict[str, float] = {}    # compile + first execution
+        #: newest retired (not dropped) request per executed model
+        self.last_retired: dict[str, ServeRequest] = {}
         self.params = MapScoreParams(alpha=alpha, beta=beta)
         self.adaptivity = adaptivity
         self.frame_drop = frame_drop
@@ -257,9 +256,12 @@ class ServingEngine:
         offline-cost-model input of the paper, measured here)."""
         self.models[handle.name] = handle
         self.drop_hist[handle.name] = []
-        # measure the real device once (includes compile), then twice timed
+        # run the real device once (includes compile) and wait for it, so
+        # the timed samples below cannot absorb its tail; then twice timed
         t = jnp.asarray(calibrate_tokens)
-        handle.fn(handle.params, t)
+        t0 = time.perf_counter()
+        jax.block_until_ready(handle.fn(handle.params, t))
+        self.warmup_s[handle.name] = time.perf_counter() - t0
         times = []
         for _ in range(2):
             t0 = time.perf_counter()
@@ -443,6 +445,7 @@ class ServingEngine:
             req.energy = vlat * acc.power
             req.done = True
             req.completion = done_at
+            self.last_retired[run_as] = req
             self._finish_stats(req)
             self._waiting.extend(queue.trigger_dependents(req.model, done_at))
 
@@ -453,7 +456,8 @@ class ServingEngine:
         energy = sum(st.energy_j for st in self.stats.per_model.values())
         per_model = {
             name: dict(frames=st.frames, violated=st.violated,
-                       energy=st.energy_j)
+                       energy=st.energy_j,
+                       uxcost=rate_dlv(st) * norm_energy(st))
             for name, st in self.stats.per_model.items()}
         return EngineReport(
             frames=frames, violated=viol, dropped=self.dropped,

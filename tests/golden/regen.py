@@ -16,12 +16,24 @@ manifest EXACTLY.  The corpus pins two contracts at once:
 Regenerate (ONLY after an intentional, reviewed behavior change):
 
     PYTHONPATH=src python tests/golden/regen.py
+
+After a library upgrade that moves results at ulp level, keep the
+committed traces and refresh only the replayed digests and UXCosts
+(first confirm with ``tests/test_vectorized_equiv.py`` that the scalar
+and vectorized engines still agree):
+
+    PYTHONPATH=src python tests/golden/regen.py --digests-only
+
+The manifest records the numpy and jax versions the digests were taken
+with under ``"stack"``; the entries live under ``"corpus"``.
 """
 from __future__ import annotations
 
+import argparse
 import hashlib
 import json
 import os
+import platform
 import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__),
@@ -86,10 +98,54 @@ def result_digest(r, fs) -> str:
     return hashlib.sha256(blob).hexdigest()
 
 
-def main() -> None:
+def stack_versions() -> dict:
+    import jax
+    import numpy
+    return {"python": platform.python_version(), "numpy": numpy.__version__,
+            "jax": jax.__version__}
+
+
+def write_manifest(corpus: dict) -> None:
+    mpath = os.path.join(GOLDEN_DIR, "manifest.json")
+    with open(mpath, "w") as f:
+        json.dump({"stack": stack_versions(), "corpus": corpus}, f,
+                  indent=1, sort_keys=True)
+        f.write("\n")
+    print(f"golden: manifest -> {mpath}")
+
+
+def refresh_digests() -> None:
+    """Replay every committed trace; rewrite only result_sha256/uxcost."""
     from repro.cluster import FleetSimulator
     from repro.cluster import trace as ftrace
-    manifest = {}
+    with open(os.path.join(GOLDEN_DIR, "manifest.json")) as f:
+        corpus = json.load(f)["corpus"]
+    for name, entry in corpus.items():
+        with open(os.path.join(GOLDEN_DIR, f"{name}.trace.json")) as f:
+            text = f.read()
+        assert hashlib.sha256(text.encode()).hexdigest() \
+            == entry["trace_sha256"], f"{name}: trace file modified"
+        fs = FleetSimulator(replay=ftrace.loads(text))
+        r = fs.run()
+        assert r.frames == entry["frames"], (name, r.frames)
+        print(f"golden: {name:16s} uxcost {entry['uxcost']!r} -> "
+              f"{r.uxcost!r}")
+        entry["result_sha256"] = result_digest(r, fs)
+        entry["uxcost"] = r.uxcost
+    write_manifest(corpus)
+
+
+def main() -> None:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--digests-only", action="store_true",
+                    help="keep the committed traces; refresh only the "
+                         "replayed result digests and UXCosts")
+    if ap.parse_args().digests_only:
+        refresh_digests()
+        return
+    from repro.cluster import FleetSimulator
+    from repro.cluster import trace as ftrace
+    corpus = {}
     for name, (kind, seed) in CORPUS.items():
         fscn, kw = build(kind, seed)
         policy = kw.pop("policy")
@@ -100,7 +156,7 @@ def main() -> None:
         path = os.path.join(GOLDEN_DIR, f"{name}.trace.json")
         with open(path, "w") as f:
             f.write(text)
-        manifest[name] = {
+        corpus[name] = {
             "kind": kind,
             "seed": seed,
             "trace_sha256": hashlib.sha256(text.encode()).hexdigest(),
@@ -110,11 +166,7 @@ def main() -> None:
         }
         print(f"golden: {name:16s} {len(text):7d} bytes  "
               f"frames={r.frames:<5d} uxcost={r.uxcost:.4f}")
-    mpath = os.path.join(GOLDEN_DIR, "manifest.json")
-    with open(mpath, "w") as f:
-        json.dump(manifest, f, indent=1, sort_keys=True)
-        f.write("\n")
-    print(f"golden: manifest -> {mpath}")
+    write_manifest(corpus)
 
 
 if __name__ == "__main__":

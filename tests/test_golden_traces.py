@@ -27,7 +27,7 @@ from repro.cluster import trace as ftrace
 GOLDEN_DIR = os.path.join(os.path.dirname(__file__), "golden")
 
 with open(os.path.join(GOLDEN_DIR, "manifest.json")) as _f:
-    MANIFEST = json.load(_f)
+    MANIFEST = json.load(_f)["corpus"]
 
 
 def _digest(r, fs) -> str:

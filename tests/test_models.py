@@ -35,6 +35,26 @@ def test_forward_shapes_and_finite(arch):
 
 
 @pytest.mark.parametrize("arch", ARCH_IDS)
+def test_compute_dtype_weights_keep_logits(arch):
+    """Serving holds matmul weights in the compute dtype. The forward casts
+    them to it anyway, so the logits are bit-identical to the float32
+    params', and making them at that dtype equals casting afterwards."""
+    cfg = smoke_config(arch)
+    p32 = M.init_params(KEY, cfg)
+    p16 = M.init_params(KEY, cfg, weight_dtype=jnp.bfloat16)
+    cast = M.cast_matmul_weights(M.init_params(KEY, cfg), jnp.bfloat16)
+    assert jax.tree.structure(p16) == jax.tree.structure(cast)
+    for a, b in zip(jax.tree.leaves(p16), jax.tree.leaves(cast)):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+    assert jnp.dtype(jnp.bfloat16) in {x.dtype for x in jax.tree.leaves(p16)}
+    tokens, fr = _inputs(cfg)
+    fwd = jax.jit(lambda p: M.forward(p, cfg, tokens, fr)[0])
+    np.testing.assert_array_equal(np.asarray(fwd(p16)), np.asarray(fwd(p32)))
+
+
+@pytest.mark.parametrize("arch", ARCH_IDS)
 def test_train_step_no_nans(arch):
     cfg = dataclasses.replace(smoke_config(arch), dtype="float32")
     tcfg = TrainConfig(optim=OptimConfig(learning_rate=1e-3, warmup_steps=1,

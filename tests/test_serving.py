@@ -83,6 +83,22 @@ def test_end_to_end_run_with_cascade():
     assert 0.0 <= report.dlv_rate <= 1.0
 
 
+def test_serve_runs_a_deployment():
+    """The entry point's serve(): every stream retires frames, the warm-up
+    is recorded, and the newest retired request keeps the engine's logits."""
+    from repro.launch.serve import Stream, serve
+    dep = (Stream("det", "gemma-2b", fps=6, seq=16, layers=1),
+           Stream("kws", "mamba2-130m", fps=6, seq=8, layers=1))
+    run = serve(dep, duration_s=1.5, adaptivity=False)
+    for st in dep:
+        assert run.report.per_model[st.name]["frames"] > 0
+        assert run.report.per_model[st.name]["uxcost"] >= 0.0
+        assert run.engine.warmup_s[st.name] > 0.0
+        req = run.engine.last_retired[st.name]
+        assert req.result.shape == (1, st.seq, 128)
+        assert not req.dropped
+
+
 def test_queue_arrival_process_streams():
     """A Poisson stream drives the queue through the same ArrivalProcess
     objects the simulator consumes; draws are reproducible (crc32 seed)."""
