@@ -15,11 +15,17 @@ Two deployments ship with it:
 
     PYTHONPATH=src python -m repro.launch.serve --duration 10
     PYTHONPATH=src python -m repro.launch.serve --published --duration 5
+    PYTHONPATH=src python -m repro.launch.serve --obs-out /tmp/obs
+
+``--obs-out DIR`` runs the engine with spans and counters on and writes
+``spans.jsonl``, ``metrics.prom`` and ``metrics.json``
+(``repro.obs.Obs.export``) there.
 """
 from __future__ import annotations
 
 import argparse
 import dataclasses
+import re
 import time
 from typing import Optional
 
@@ -39,7 +45,9 @@ def build_handle(arch: str, name: str, *, layers: int | None = None,
 
     ``published=True`` serves ``get_config(arch)`` unchanged; otherwise
     the reduced ``smoke_config`` with a 128-token vocabulary, optionally
-    cut further to ``layers`` / ``d_model``.
+    cut further to ``layers`` / ``d_model``. The jitted function is named
+    ``forward_<name>`` (non-word characters as ``_``), so the XLA module
+    names in a device trace say which stream's model ran.
     """
     if published:
         if layers or d_model:
@@ -59,12 +67,14 @@ def build_handle(arch: str, name: str, *, layers: int | None = None,
     params = M.init_params(jax.random.PRNGKey(seed), cfg,
                            weight_dtype=M.compute_dtype(cfg))
 
-    @jax.jit
     def forward_logits(p, tokens):
         logits, _ = M.forward(p, cfg, tokens)
         return logits
 
-    return ModelHandle(name=name, cfg=cfg, params=params, fn=forward_logits)
+    forward_logits.__name__ = forward_logits.__qualname__ = (
+        "forward_" + re.sub(r"[^A-Za-z0-9_]", "_", name))
+    return ModelHandle(name=name, cfg=cfg, params=params,
+                       fn=jax.jit(forward_logits))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -107,8 +117,11 @@ class ServeRun:
 def serve(deployment: tuple[Stream, ...] = TOY, *, published: bool = False,
           duration_s: float = 8.0, fps_scale: float = 1.0,
           frame_drop: bool = True, supernet: bool = True,
-          adaptivity: bool = True, seed: int = 0) -> ServeRun:
-    """Build, calibrate and run one deployment on the real clock."""
+          adaptivity: bool = True, seed: int = 0, obs=None) -> ServeRun:
+    """Build, calibrate and run one deployment on the real clock.
+
+    ``obs`` is the engine's (``repro.obs.Obs.make``): the run's spans and
+    counters are then in ``run.engine.obs``."""
     # heterogeneous 3-slice system (a big fast slice + two small efficient)
     accs = [
         VirtualAccelerator("big0", speed=1.0, power=1.0),
@@ -117,7 +130,7 @@ def serve(deployment: tuple[Stream, ...] = TOY, *, published: bool = False,
     ]
     engine = ServingEngine(accs, adaptivity=adaptivity,
                            frame_drop=frame_drop,
-                           supernet_switch=supernet, seed=seed)
+                           supernet_switch=supernet, seed=seed, obs=obs)
     handles: dict[str, ModelHandle] = {}
     for st in deployment:
         t0 = time.perf_counter()
@@ -152,7 +165,7 @@ def serve(deployment: tuple[Stream, ...] = TOY, *, published: bool = False,
     return ServeRun(engine=engine, report=report, handles=handles)
 
 
-def main() -> None:
+def main(argv: Optional[list[str]] = None) -> None:
     from repro.launch.cache import enable_compile_cache
 
     ap = argparse.ArgumentParser()
@@ -164,12 +177,20 @@ def main() -> None:
     ap.add_argument("--no-supernet", action="store_true")
     ap.add_argument("--no-adapt", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    ap.add_argument("--obs-out", metavar="DIR",
+                    help="record the engine's spans and counters and write "
+                         "spans.jsonl and metrics.prom to DIR")
+    args = ap.parse_args(argv)
     enable_compile_cache()
-    serve(PUBLISHED if args.published else TOY, published=args.published,
-          duration_s=args.duration, frame_drop=not args.no_drop,
-          supernet=not args.no_supernet, adaptivity=not args.no_adapt,
-          seed=args.seed)
+    obs = ({"spans": True, "metrics": True, "profile": False}
+           if args.obs_out else None)
+    run = serve(PUBLISHED if args.published else TOY,
+                published=args.published, duration_s=args.duration,
+                frame_drop=not args.no_drop, supernet=not args.no_supernet,
+                adaptivity=not args.no_adapt, seed=args.seed, obs=obs)
+    if args.obs_out:
+        for path in run.engine.obs.export(args.obs_out).values():
+            print(f"[serve] wrote {path}")
 
 
 if __name__ == "__main__":

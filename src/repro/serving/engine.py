@@ -14,7 +14,11 @@ executions here. The engine owns:
   * the four DREAM engines: MapScore calculator, frame-drop, adaptivity
     ((alpha, beta) UXCost feedback), and job assignment/dispatch,
   * straggler mitigation: jobs whose wall-clock exceeds a p99 watermark are
-    re-dispatched to the next-best slice (MapScore already ranks them).
+    re-dispatched to the next-best slice (MapScore already ranks them),
+  * observability (``obs=``, the :class:`repro.obs.Obs` bundle the fleet
+    takes): ``serve.*`` spans around each phase of the loop and a ``job``
+    span per frame on the engine's clock, each loop span also a profiler
+    annotation carrying its ``sid``, and dispatch / switch / turn counters.
 
 Energy on the dev box is modeled as latency x slice power weight (real
 deployments plug in measured per-accelerator power).
@@ -35,6 +39,7 @@ import numpy as np
 from repro.configs import ArchConfig
 from repro.core.mapscore import MapScoreParams
 from repro.core.uxcost import WindowStats, norm_energy, rate_dlv, uxcost
+from repro.obs import Obs
 
 
 # ---------------------------------------------------------------------------
@@ -178,7 +183,6 @@ class VirtualAccelerator:
     power: float = 1.0          # relative energy per unit work
     busy_until: float = 0.0
     last_model: Optional[str] = None
-    total_busy: float = 0.0
 
 
 @dataclass
@@ -223,7 +227,7 @@ class ServingEngine:
                  max_drop_per_window: int = 2, drop_window: int = 10,
                  straggler_factor: float = 3.0,
                  stale_periods: float = 2.0,
-                 seed: int = 0):
+                 seed: int = 0, obs=None):
         self.accs = accelerators
         self.models: dict[str, ModelHandle] = {}
         self.lat_table: dict[tuple[str, str], float] = {}  # (model, acc) -> s
@@ -248,6 +252,8 @@ class ServingEngine:
         self._probe: list[tuple[float, np.ndarray]] = []
         self._probe_radius = 0.4
         self._lat_samples: dict[str, list[float]] = {}
+        #: ``Obs.make(obs)``; read once per ``run`` (None: no hook runs)
+        self.obs = Obs.make(obs)
 
     # ------------------------------------------------------------ registry
     def register(self, handle: ModelHandle, calibrate_tokens: np.ndarray
@@ -291,14 +297,15 @@ class ServingEngine:
                 + self.params.beta * score_energy)
 
     # ----------------------------------------------------------- frame drop
-    def _try_drop(self, now: float) -> None:
+    def _try_drop(self, now: float) -> Optional[ServeRequest]:
+        """Drop at most one frame expected to miss; returns it, or None."""
         waiting = [r for r in self._waiting if not r.done]
         expected_viol = [
             r for r in waiting
             if min(self.lat_table[(r.model, a.name)] for a in self.accs)
             > max(r.deadline - now, 0.0)]
         if len(expected_viol) < 2:
-            return
+            return None
         best, best_ratio = None, 0.0
         for r in expected_viol:
             hist = self.drop_hist[r.model][-self.drop_window:]
@@ -312,6 +319,7 @@ class ServingEngine:
             best.done, best.dropped = True, True
             self.dropped += 1
             self._finish_stats(best)
+        return best
 
     # ------------------------------------------------------------ adaptivity
     def _adapt(self, window_ux: float) -> None:
@@ -367,16 +375,24 @@ class ServingEngine:
         """Drive the engine on the real clock until duration_s elapses."""
         t_start = time.perf_counter()
         now_fn = lambda: time.perf_counter() - t_start
+        rec = _Recorder(self.obs, now_fn) if self.obs is not None else None
         self._waiting: list[ServeRequest] = []
         next_window = window_s
-        variant_counts: dict[str, int] = {}
 
         while True:
             now = now_fn()
             if now >= duration_s:
                 break
-            self._waiting.extend(queue.poll(now))
+            if rec is not None:
+                rec.open("serve.poll")
+            polled = queue.poll(now)
+            if rec is not None:
+                rec.close(frames=len(polled))
+                rec.admit(polled, now)
+            self._waiting.extend(polled)
             self._waiting = [r for r in self._waiting if not r.done]
+            if rec is not None:
+                rec.open("serve.sweep")
             # hygiene: a frame still waiting `stale_periods` past its
             # deadline-equivalent period is abandoned (counts violated)
             for r in self._waiting:
@@ -385,16 +401,26 @@ class ServingEngine:
                     r.done, r.dropped = True, True
                     self.aborted += 1
                     self._finish_stats(r)
+                    if rec is not None:
+                        rec.drop(r, "stale")
             self._waiting = [r for r in self._waiting if not r.done]
             if self.frame_drop:
-                self._try_drop(now)
+                dropped = self._try_drop(now)
+                if rec is not None and dropped is not None:
+                    rec.drop(dropped, "drop")
+            if rec is not None:
+                rec.close()
             ready = [r for r in self._waiting if not r.done]
             idle = [a for a in self.accs if a.busy_until <= now]
             if not ready or not idle:
+                if rec is not None:
+                    rec.idle("hold" if ready else "no_frame", len(ready))
                 nxt = min([a.busy_until for a in self.accs
                            if a.busy_until > now] + [now + 1e-3])
                 time.sleep(max(min(nxt - now, 1e-3), 1e-5))
                 if now >= next_window:
+                    if rec is not None:
+                        rec.open("serve.adapt")
                     wux = uxcost(self.window_stats)
                     if self.adaptivity and sum(
                             st.frames for st in
@@ -403,9 +429,15 @@ class ServingEngine:
                     self.stats.merge(self.window_stats)
                     self.window_stats = WindowStats()
                     next_window += window_s
+                    if rec is not None:
+                        rec.close(alpha=self.params.alpha,
+                                  beta=self.params.beta)
                 continue
 
             # job assignment: best (request, accelerator) MapScore pair
+            if rec is not None:
+                rec.end_idle()
+                rec.open("serve.decide")
             best, best_score = None, -np.inf
             for r in ready:
                 for a in idle:
@@ -414,17 +446,32 @@ class ServingEngine:
                         best, best_score = (r, a), s
             req, acc = best
             run_as = self._pick_variant(req, now)
-            variant_counts[run_as] = variant_counts.get(run_as, 0) + 1
+            if rec is not None:
+                rec.decided(req, run_as, acc.name, best_score,
+                            len(ready) * len(idle))
             handle = self.models[run_as]
             tok = req.tokens
             if tok.shape[1] > 0:
                 t0 = time.perf_counter()
-                out = handle.fn(handle.params, jnp.asarray(tok))
+                if rec is not None:
+                    rec.open("serve.h2d", rid=req.rid)
+                x = jnp.asarray(tok)
+                if rec is not None:
+                    rec.close(bytes=int(tok.nbytes))
+                    rec.open("serve.launch", rid=req.rid, run_as=run_as)
+                out = handle.fn(handle.params, x)
+                if rec is not None:
+                    rec.close()
+                    rec.open("serve.wait", rid=req.rid)
                 jax.block_until_ready(out)
+                if rec is not None:
+                    rec.close()
                 wall = time.perf_counter() - t0
                 req.result = out
             else:
                 wall = 0.0
+            if rec is not None:
+                rec.open("serve.retire", rid=req.rid)
             # straggler mitigation: re-dispatch if way past expectation
             expect = self.lat_table[(run_as, acc.name)]
             samples = self._lat_samples.setdefault(run_as, [])
@@ -440,15 +487,19 @@ class ServingEngine:
             vlat = max(wall, self.lat_table[(run_as, acc.name)])
             done_at = now + vlat
             acc.busy_until = done_at
-            acc.total_busy += vlat
             acc.last_model = run_as
             req.energy = vlat * acc.power
             req.done = True
             req.completion = done_at
             self.last_retired[run_as] = req
             self._finish_stats(req)
-            self._waiting.extend(queue.trigger_dependents(req.model, done_at))
+            children = queue.trigger_dependents(req.model, done_at)
+            self._waiting.extend(children)
+            if rec is not None:
+                rec.retired(req, acc.name, children)
 
+        if rec is not None:
+            rec.finish()
         self.stats.merge(self.window_stats)
         self.window_stats = WindowStats()
         frames = sum(st.frames for st in self.stats.per_model.values())
@@ -466,3 +517,151 @@ class ServingEngine:
             dlv_rate=viol / frames if frames else 0.0,
             energy=energy, per_model=per_model,
             alpha=self.params.alpha, beta=self.params.beta)
+
+
+class _Recorder:
+    """The hooks of one ``ServingEngine.run`` with ``obs`` on.
+
+    Spans go to ``obs.tracer`` on the engine's clock (seconds since ``run``
+    began, the ``now`` the queue is given). Each ``serve.*`` span is also,
+    while it is open, a ``jax.profiler.TraceAnnotation`` of the same name
+    with its ``sid`` as metadata, so that a profiler trace's host events
+    pair with the records by ``sid`` and the two clocks can be tied. The
+    loop spans nest: ``serve.idle`` (coalesced over consecutive sleeping
+    turns of one reason) holds those turns' ``serve.poll``,
+    ``serve.sweep`` and ``serve.adapt``; the others follow one another.
+    A ``job`` span per frame runs from its admission to its retire or drop
+    in the simulator's schema, so ``critical_path`` explains a served frame.
+    Counters go to ``obs.metrics``. Nothing here feeds a decision.
+    """
+
+    def __init__(self, obs: Obs, clock: Callable[[], float]):
+        self.tracer = obs.tracer
+        self.clock = clock
+        self._stack: list[tuple] = []              # (kind, sid, ann, t0)
+        self._idle: Optional[list] = None          # [sid, ann, reason, ready]
+        self._jobs: dict[int, int] = {}            # rid -> job sid
+        self._seg: Optional[list[float]] = None    # this dispatch's exec
+        self._slice: Optional[str] = None          # this dispatch's choice
+        m = obs.metrics
+        if m is None:
+            self.m_dispatch = self.m_switch = self.m_turn = None
+        else:
+            self.m_dispatch = m.counter(
+                "serve_dispatch_total", "frames dispatched, by the model "
+                "that ran and its slice", ("model", "slice"))
+            self.m_switch = m.counter(
+                "serve_switch_total", "supernet switches: a frame served by "
+                "a lighter variant", ("model", "variant"))
+            self.m_turn = m.counter(
+                "serve_turn_total", "engine loop turns: a dispatch, or a "
+                "sleep with no frame ready or every ready frame held",
+                ("kind",))
+
+    # ----------------------------------------------------------- loop spans
+    def _enter(self, kind: str, **attrs) -> tuple[int, Any, float]:
+        t = self.clock()
+        sid = self.tracer.open(kind, t, **attrs)
+        ann = jax.profiler.TraceAnnotation(kind, sid=sid)
+        ann.__enter__()
+        return sid, ann, t
+
+    def _exit(self, sid: int, ann, **attrs) -> float:
+        ann.__exit__(None, None, None)
+        t = self.clock()
+        self.tracer.close(sid, t, **attrs)
+        return t
+
+    def open(self, kind: str, **attrs) -> None:
+        if self.tracer is not None:
+            self._stack.append((kind, *self._enter(kind, **attrs)))
+
+    def close(self, **attrs) -> Optional[float]:
+        """Close the innermost open loop span; returns its end."""
+        if self.tracer is None:
+            return None
+        kind, sid, ann, t0 = self._stack.pop()
+        t1 = self._exit(sid, ann, **attrs)
+        if kind == "serve.launch":
+            self._seg = [t0, t1]
+        elif kind == "serve.wait":
+            self._seg[1] = t1
+        return t1
+
+    def idle(self, reason: str, ready: int) -> None:
+        """A sleeping turn: extend the open ``serve.idle`` span if its
+        reason holds, else close it and open one for ``reason``."""
+        if self.m_turn is not None:
+            self.m_turn.inc(kind=reason)
+        if self.tracer is None:
+            return
+        cur = self._idle
+        if cur is not None and cur[2] == reason:
+            cur[3] = max(cur[3], ready)
+            return
+        self.end_idle()
+        self._idle = [*self._enter("serve.idle")[:2], reason, ready]
+
+    def end_idle(self) -> None:
+        if self._idle is not None:
+            sid, ann, reason, ready = self._idle
+            self._exit(sid, ann, reason=reason, ready=ready)
+            self._idle = None
+
+    # ------------------------------------------------------------ decisions
+    def decided(self, req: ServeRequest, run_as: str, slice_: str,
+                score: float, pairs: int) -> None:
+        """Close ``serve.decide`` and count the dispatch."""
+        if self.tracer is not None:
+            self.close(rid=req.rid, model=req.model, run_as=run_as,
+                       slice=slice_, score=float(score), pairs=pairs)
+            self._seg, self._slice = None, slice_
+        if self.m_turn is not None:
+            self.m_turn.inc(kind="dispatch")
+            self.m_dispatch.inc(model=run_as, slice=slice_)
+            if run_as != req.model:
+                self.m_switch.inc(model=req.model, variant=run_as)
+
+    # ----------------------------------------------------------------- jobs
+    def admit(self, reqs: list[ServeRequest], t: float,
+              parent: Optional[int] = None) -> None:
+        if self.tracer is None:
+            return
+        for r in reqs:
+            self._jobs[r.rid] = self.tracer.open(
+                "job", t, uid=r.rid, model=r.model, origin=r.arrival,
+                deadline=r.deadline, parent=parent)
+
+    def drop(self, req: ServeRequest, reason: str) -> None:
+        if self.tracer is None:
+            return
+        t = self.clock()
+        self.tracer.event("serve.drop", t, rid=req.rid, reason=reason)
+        self.tracer.close(self._jobs.pop(req.rid), t, outcome="dropped",
+                          segs=[], tail=True)
+
+    def retired(self, req: ServeRequest, slice_: str,
+                children: list[ServeRequest]) -> None:
+        """Close ``serve.retire`` (``slice_``: where the books put the
+        frame, another than decided if it was re-dispatched) and the
+        frame's job; open its children's jobs with it as their parent."""
+        if self.tracer is None:
+            return
+        t = self.close(redispatched=slice_ != self._slice,
+                       children=len(children))
+        self.tracer.close(self._jobs.pop(req.rid), t, outcome="done",
+                          segs=[self._seg] if self._seg else [],
+                          tail=not children)
+        self.admit(children, t, parent=req.rid)
+
+    def finish(self) -> None:
+        """At the window's close: end the idle span; frames still
+        waiting close ``unfinished``."""
+        if self.tracer is None:
+            return
+        self.end_idle()
+        t = self.clock()
+        for sid in self._jobs.values():
+            self.tracer.close(sid, t, outcome="unfinished", segs=[],
+                              tail=True)
+        self._jobs.clear()
