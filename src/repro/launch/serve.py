@@ -25,9 +25,10 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import re
 import time
-from typing import Optional
+from typing import Callable, Optional
 
 import jax
 import numpy as np
@@ -36,6 +37,12 @@ from repro.configs import get_config, smoke_config
 from repro.models import model as M
 from repro.serving import (EngineReport, ModelHandle, RequestQueue,
                            ServingEngine, VirtualAccelerator)
+
+
+#: (config, weight dtype) -> the jitted ``init_params`` of that model: its
+#: seeded weights as one program. The key is the argument, not a constant,
+#: so every seed and every stream of one config run the same executable.
+_INIT: dict[tuple, Callable] = {}
 
 
 def build_handle(arch: str, name: str, *, layers: int | None = None,
@@ -47,7 +54,9 @@ def build_handle(arch: str, name: str, *, layers: int | None = None,
     the reduced ``smoke_config`` with a 128-token vocabulary, optionally
     cut further to ``layers`` / ``d_model``. The jitted function is named
     ``forward_<name>`` (non-word characters as ``_``), so the XLA module
-    names in a device trace say which stream's model ran.
+    names in a device trace say which stream's model ran. The weights come
+    from one jitted ``init_params``; ``init_program`` says whether this
+    handle compiled it ("compiled") or found it made ("reused").
     """
     if published:
         if layers or d_model:
@@ -64,8 +73,14 @@ def build_handle(arch: str, name: str, *, layers: int | None = None,
         cfg = dataclasses.replace(cfg, **upd)
     # matmul weights held in the compute dtype the forward casts them to:
     # the same logits, at half the memory and no per-call weight copy
-    params = M.init_params(jax.random.PRNGKey(seed), cfg,
-                           weight_dtype=M.compute_dtype(cfg))
+    dtype = M.compute_dtype(cfg)
+    init = _INIT.get((cfg, dtype))
+    if init is None:
+        init = _INIT[cfg, dtype] = jax.jit(functools.partial(
+            M.init_params, cfg=cfg, weight_dtype=dtype))
+    programs = init._cache_size()
+    params = init(jax.random.PRNGKey(seed))
+    grew = init._cache_size() > programs
 
     def forward_logits(p, tokens):
         logits, _ = M.forward(p, cfg, tokens)
@@ -74,7 +89,8 @@ def build_handle(arch: str, name: str, *, layers: int | None = None,
     forward_logits.__name__ = forward_logits.__qualname__ = (
         "forward_" + re.sub(r"[^A-Za-z0-9_]", "_", name))
     return ModelHandle(name=name, cfg=cfg, params=params,
-                       fn=jax.jit(forward_logits))
+                       fn=jax.jit(forward_logits),
+                       init_program="compiled" if grew else "reused")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -131,12 +147,20 @@ def serve(deployment: tuple[Stream, ...] = TOY, *, published: bool = False,
     engine = ServingEngine(accs, adaptivity=adaptivity,
                            frame_drop=frame_drop,
                            supernet_switch=supernet, seed=seed, obs=obs)
+    inits = None
+    if engine.obs is not None and engine.obs.metrics is not None:
+        inits = engine.obs.metrics.counter(
+            "serve_init_total", "handles built, by whether the "
+            "seeded-weights program was compiled or reused",
+            ("model", "program"))
     handles: dict[str, ModelHandle] = {}
     for st in deployment:
         t0 = time.perf_counter()
         h = build_handle(st.arch, st.name, layers=st.layers, seed=seed,
                          published=published)
         handles[st.name] = h
+        if inits is not None:
+            inits.inc(model=st.name, program=h.init_program)
         # calibrate with the stream shape: a recompile at dispatch time
         # would poison the wall-clock accounting
         engine.register(h, np.zeros((1, st.seq), np.int32))

@@ -16,6 +16,7 @@ API (all functional, params are plain dict pytrees):
 """
 from __future__ import annotations
 
+import functools
 from typing import Callable, Optional
 
 import jax
@@ -199,7 +200,12 @@ def init_params(key: Array, cfg: ArchConfig,
     """Seeded parameters, float32 by default. ``weight_dtype`` holds the
     matmul weights in that dtype as each group is made (the values of
     ``cast_matmul_weights`` applied afterwards), so a served model never
-    holds all of its float32 weights at once."""
+    holds all of its float32 weights at once.
+
+    The block groups are drawn by one scan over the group keys (a
+    ``lax.map``), so only one group's float32 draws are alive at a time
+    and a jitted call is one program, not a dispatch per draw, cast and
+    stack."""
     pat = group_pattern(cfg)
     g = num_groups(cfg)
     keys = jax.random.split(key, 4)
@@ -221,27 +227,25 @@ def init_params(key: Array, cfg: ArchConfig,
                                    gated=cfg.mlp_gated),
         }
     cast_matmul_weights(params, weight_dtype)
-    gkeys = jax.random.split(keys[3], g)
-
-    def one_group(k):
-        bkeys = jax.random.split(k, len(pat))
-        return {str(i): _block_init(bkeys[i], cfg, kind)
-                for i, kind in enumerate(pat)}
-
-    # stack one leaf at a time and release its per-group parts as it goes:
-    # the peak is all groups plus one stacked leaf, not all groups twice
-    cols = []
-    for k in gkeys:
-        leaves, treedef = jax.tree.flatten(
-            cast_matmul_weights(one_group(k), weight_dtype))
-        cols.append(leaves)
-    stacked = []
-    for i in range(len(cols[0])):
-        stacked.append(jnp.stack([c[i] for c in cols]))
-        for c in cols:
-            c[i] = None
-    params["blocks"] = jax.tree.unflatten(treedef, stacked)
+    _, params["blocks"] = jax.lax.scan(_group_init(cfg, weight_dtype), None,
+                                       jax.random.split(keys[3], g))
     return params
+
+
+@functools.lru_cache(maxsize=None)
+def _group_init(cfg: ArchConfig, weight_dtype) -> Callable:
+    """The scan body that makes one group's blocks from its key, cast: a
+    ``lax.map`` body, kept as one function per (config, dtype) so that an
+    eager ``init_params`` traces and compiles its scan once, not per call."""
+    pat = group_pattern(cfg)
+
+    def one_group(carry, k):
+        bkeys = jax.random.split(k, len(pat))
+        return carry, cast_matmul_weights(
+            {str(i): _block_init(bkeys[i], cfg, kind)
+             for i, kind in enumerate(pat)}, weight_dtype)
+
+    return one_group
 
 
 def cast_matmul_weights(params: dict, dtype) -> dict:

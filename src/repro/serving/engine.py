@@ -192,6 +192,9 @@ class ModelHandle:
     params: Any
     fn: Callable                # jitted logits fn(params, tokens)
     supernet: tuple[str, ...] = ()   # lighter variant model names
+    #: ``build_handle``'s seeded-weights program: "compiled" for this
+    #: handle, or "reused" from an earlier handle of the same config
+    init_program: Optional[str] = None
 
 
 # ---------------------------------------------------------------------------

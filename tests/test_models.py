@@ -95,6 +95,84 @@ def test_decode_matches_forward_fp32(arch):
                                atol=1e-3, rtol=1e-3)
 
 
+def _init_params_group_loop(key, cfg, weight_dtype):
+    """``init_params`` as it was before the block groups were drawn by
+    ``lax.map``: eagerly, group by group, each group cast, then stacked
+    leaf by leaf. The oracle of the jitted init's leaves and values."""
+    from repro.models import attention as attn, layers
+    pat = M.group_pattern(cfg)
+    keys = jax.random.split(key, 4)
+    params = {
+        "embed": layers.embedding_init(keys[0], cfg.vocab_size, cfg.d_model,
+                                       cfg.tie_embeddings),
+        "final_norm": layers.rmsnorm_init(cfg.d_model),
+    }
+    if cfg.frontend:
+        params["frontend"] = {"proj": layers.dense_init(
+            keys[1], (cfg.frontend_dim, cfg.d_model), cfg.frontend_dim)}
+    if cfg.shared_attn_every:
+        ks = jax.random.split(keys[2], 3)
+        params["shared_attn"] = {
+            "ln": layers.rmsnorm_init(2 * cfg.d_model),
+            "attn": attn.attn_init(ks[0], M.shared_attn_cfg_for(cfg)),
+            "ln2": layers.rmsnorm_init(cfg.d_model),
+            "mlp": layers.mlp_init(ks[1], cfg.d_model, cfg.d_ff,
+                                   gated=cfg.mlp_gated),
+        }
+    M.cast_matmul_weights(params, weight_dtype)
+    cols = []
+    for k in jax.random.split(keys[3], M.num_groups(cfg)):
+        bkeys = jax.random.split(k, len(pat))
+        group = {str(i): M._block_init(bkeys[i], cfg, kind)
+                 for i, kind in enumerate(pat)}
+        leaves, treedef = jax.tree.flatten(
+            M.cast_matmul_weights(group, weight_dtype))
+        cols.append(leaves)
+    params["blocks"] = jax.tree.unflatten(
+        treedef, [jnp.stack(c) for c in zip(*cols)])
+    return params
+
+
+INIT_ARCHS = {
+    "mamba2-130m": {},
+    # the published 6-block group of mamba blocks around the shared block
+    "zamba2-2.7b": {"shared_attn_every": 6, "num_layers": 12},
+    "phi-3-vision-4.2b": {},
+    "phi3.5-moe-42b-a6.6b": {},
+}
+
+
+#: float32 leaves computed (not drawn) from a draw, where XLA's fusion in
+#: one program may round the last bit differently from eager steps: the
+#: SSM's inverse softplus of exp(uniform), ``dt_bias``
+ONE_ULP_LEAVES = {"dt_bias"}
+
+
+@pytest.mark.parametrize("arch", sorted(INIT_ARCHS))
+def test_jitted_init_params_equals_group_loop(arch):
+    """One jitted ``init_params`` (as ``build_handle`` runs it) draws the
+    same leaves as the eager group-by-group construction: same keys, draw
+    order, shapes and dtypes, and the same bits, but for the last bit of
+    the leaves in ``ONE_ULP_LEAVES``."""
+    cfg = dataclasses.replace(smoke_config(arch), **INIT_ARCHS[arch])
+    dt = M.compute_dtype(cfg)
+    init = jax.jit(M.init_params, static_argnames=("cfg", "weight_dtype"))
+    got = jax.tree_util.tree_flatten_with_path(
+        init(KEY, cfg=cfg, weight_dtype=dt))[0]
+    want = jax.tree_util.tree_flatten_with_path(
+        _init_params_group_loop(KEY, cfg, dt))[0]
+    assert [p for p, _ in got] == [p for p, _ in want]
+    assert jnp.dtype(dt) in {x.dtype for _, x in got}
+    for (path, a), (_, b) in zip(got, want):
+        assert (a.shape, a.dtype) == (b.shape, b.dtype), path
+        a, b = np.asarray(a), np.asarray(b)
+        if a.tobytes() == b.tobytes():
+            continue
+        assert path[-1].key in ONE_ULP_LEAVES and a.dtype == np.float32, path
+        np.testing.assert_array_less(np.abs(a - b),
+                                     1.5 * np.spacing(np.abs(b)))
+
+
 def test_scan_equals_loop():
     cfg = smoke_config("gemma2-2b")
     cfg_scan = dataclasses.replace(cfg, num_layers=4, scan_layers=True,

@@ -99,6 +99,36 @@ def test_serve_runs_a_deployment():
         assert not req.dropped
 
 
+def test_init_program_compiled_once_per_config(monkeypatch):
+    """Handles of one architecture share one compiled seeded-weights
+    program: the key is its argument, so seeds differ in values only;
+    serve(obs=) counts each handle's program as compiled or reused."""
+    import jax
+    from repro.launch import serve as serve_mod
+    from repro.launch.serve import Stream, serve
+
+    monkeypatch.setattr(serve_mod, "_INIT", {})
+    a = build_handle("mamba2-130m", "a", layers=1, seed=1)
+    b = build_handle("mamba2-130m", "b", layers=1, seed=2)
+    assert (a.init_program, b.init_program) == ("compiled", "reused")
+    [init] = serve_mod._INIT.values()
+    assert init._cache_size() == 1
+    assert jax.tree.structure(a.params) == jax.tree.structure(b.params)
+    for part in ("embed", "blocks"):
+        assert any(not np.array_equal(np.asarray(x), np.asarray(y))
+                   for x, y in zip(jax.tree.leaves(a.params[part]),
+                                   jax.tree.leaves(b.params[part]))), part
+
+    monkeypatch.setattr(serve_mod, "_INIT", {})
+    dep = (Stream("kws", "mamba2-130m", fps=6, seq=8, layers=1),
+           Stream("ctx", "mamba2-130m", fps=6, seq=8, layers=1))
+    run = serve(dep, duration_s=0.5, adaptivity=False,
+                obs={"spans": False, "metrics": True, "profile": False})
+    inits = run.engine.obs.metrics._metrics["serve_init_total"]
+    assert inits.children == {("kws", "compiled"): 1.0,
+                              ("ctx", "reused"): 1.0}
+
+
 def test_queue_arrival_process_streams():
     """A Poisson stream drives the queue through the same ArrivalProcess
     objects the simulator consumes; draws are reproducible (crc32 seed)."""
