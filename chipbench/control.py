@@ -8,12 +8,12 @@ For every seed, in one process: a run of the cell as the benchmark makes
 it (models built from the seed, the cell's own sessions and traffic, a
 short window), then the comparison of the frames it kept, once for the
 program and once for the reference's float8 control put in its place
-(``chipbench/reference.py``, ``control=True``). One JSON line per seed
-gives each reading. The last line gives, per stream and number, the lower
-reading (the largest of the program's), the upper one (the smallest of
-the control's) and the limit that ``limit`` sets between them, where the
-upper is at least three times the lower. Benchmark runs never run the
-control.
+(the model's ``forward(..., control=True)``, ``run.model_module``). One
+JSON line per seed gives each reading. The last line gives, per stream
+and number, the lower reading (the largest of the program's), the upper
+one (the smallest of the control's) and the limit that ``limit`` sets
+between them, where the upper is at least three times the lower.
+Benchmark runs never run the control.
 """
 from __future__ import annotations
 

@@ -17,21 +17,28 @@ deployment ``chipbench/configs/<config>.json``. A run:
    benchmark's own ``SessionQueue``: that call is the timed window;
 5. checks what the window produced (``correct``): the engine's books
    against the frames the queue saw, and the logits of a seeded sample
-   of finished frames of every stream against ``chipbench/reference.py``;
+   of finished frames of every stream against the model's reference
+   (``model_module``);
 6. prints one JSON line last on standard output: ``correct``,
    ``attempted``, ``failed``, ``metrics`` (the cell's end-to-end metrics,
    or with ``--trace 1`` its per-layer ones), ``device``, with
    ``--trace 1`` a ``breakdown``, and last ``checks``, each compared
    number beside its limit (also the last lines of standard error).
 
-Every metric is computed by ``chipbench/metrics/<name>.py``, found by
-the names in ``BENCHMARK.json``: this file names no cell, configuration
-or metric.
+Every metric is computed by ``chipbench/metrics/<name>.py``, and each
+model's reference, FLOPs and bytes by ``chipbench/models/<model>.py``
+where that exists (else ``reference.py`` and ``flops.py``), found by the
+names in ``BENCHMARK.json`` and the configuration: this file names no
+cell, configuration, model or metric.
 
 With ``--trace 1`` the last ``TRACE_S`` seconds of the window run under
 the profiler, the handles' calls and the queue's calls carry host
-annotations, and the per-layer metrics read the trace; the queue-side
-ones are taken over the frames due before the trace began.
+annotations, the engine records its spans and counters
+(``engine_trace.OBS``), and the per-layer metrics read the trace and the
+spans; the queue-side and span ones are taken over the window before the
+trace began. The result line then carries ``spans``: the traced window's
+device-idle seconds by innermost engine span, the clock tie, and the
+longest stalls of the engine loop (``engine_trace``).
 
 The run exits non-zero without a result line when JAX finds no TPU, fewer
 chips than the cell asks for, or a device kind missing from
@@ -53,10 +60,12 @@ import os  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 from dataclasses import dataclass, field  # noqa: E402
+from types import SimpleNamespace  # noqa: E402
 from typing import Any, Callable  # noqa: E402
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.dirname(os.path.abspath(__file__))
+MODELS = os.path.join(BENCH, "models")
 TRACE_S = 2.0          # traced seconds at the end of a --trace 1 window
 MAX_SEED = 2 ** 31 - 1
 
@@ -85,14 +94,34 @@ def load_cell(name: str) -> tuple[dict, dict, dict]:
     return bench, work, cfg
 
 
-def reader(metric: str) -> Callable:
-    """``read(run)`` of ``metrics/<metric>.py``."""
-    path = os.path.join(BENCH, "metrics", f"{metric}.py")
-    spec = importlib.util.spec_from_file_location(
-        f"chipbench.metrics.{metric}", path)
+def _load(name: str, path: str):
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def reader(metric: str) -> Callable:
+    """``read(run)`` of ``metrics/<metric>.py``."""
+    return _load(f"chipbench.metrics.{metric}",
+                 os.path.join(BENCH, "metrics", f"{metric}.py")).read
+
+
+def model_module(model: str):
+    """What the benchmark knows of one model (a key of a configuration's
+    ``models``): ``models/<model>.py`` where it exists, else
+    ``reference.py``'s ``forward`` and ``flops.py``'s counts. The module
+    gives ``forward(sizes, seed, tokens, control=False)`` (float32 logits
+    of the plain reference; ``control``: its low-precision control),
+    ``forward_flops(sizes, s)``, ``forward_bytes(sizes, s)`` and, where
+    it names kernels, ``kernels(sizes, s)`` (``Run.kernel_roofline``)."""
+    path = os.path.join(MODELS, f"{model}.py")
+    if os.path.exists(path):
+        return _load(f"chipbench.models.{model}", path)
+    from chipbench import flops, reference
+    return SimpleNamespace(forward=reference.forward,
+                           forward_flops=flops.forward_flops,
+                           forward_bytes=flops.forward_bytes)
 
 
 def metrics_for(bench: dict, cell: str, traced: bool) -> list[dict]:
@@ -118,13 +147,46 @@ class Run:
     flops: dict = field(default_factory=dict)     # stream -> per forward
     bytes: dict = field(default_factory=dict)     # stream -> per forward
     peak: dict = field(default_factory=dict)      # peaks.json entry
+    modules: dict = field(default_factory=dict)   # stream -> model_module
+    sizes: dict = field(default_factory=dict)     # stream -> model sizes
+    prompts: dict = field(default_factory=dict)   # stream -> positions
     trace: Any = None                # trace.Summary of a --trace 1 run
+    spans: Any = None                # {"records", "counters"}, --trace 1
 
     def judged(self, until: float) -> list:
         """Frames due before ``until`` whose outcome is known: left out are
         those unfinished at the close whose deadline lies beyond it."""
         return [f for f in self.frames if f.due < until and not (
             f.outcome == "pending" and f.deadline > self.seconds)]
+
+    def kernel_roofline(self, label: str) -> float | None:
+        """Share of its roofline reached by the kernel ``label`` in the
+        traced interval, over every stream whose model module names it in
+        ``kernels(sizes, s)``: ``{label: {"flops", "bytes", "ops"}}``, the
+        FLOPs and bytes of all its calls in one forward, and ``ops(name,
+        kind)``, true for the device operations (``trace.Summary.ops``)
+        that are this kernel. The least time, forwards run times the
+        larger of FLOPs over the bf16 peak and bytes over HBM bandwidth,
+        over the matched operations' self time. None where nothing
+        matched."""
+        t = self.trace
+        if t is None:
+            return None
+        least = spent = 0.0
+        for stream, mod in self.modules.items():
+            named = getattr(mod, "kernels", None)
+            k = named and named(self.sizes[stream],
+                                self.prompts[stream]).get(label)
+            runs = t.runs.get(stream, [0])[0]
+            if not k or not runs:
+                continue
+            prefix = f"{stream}:"
+            spent += sum(sec for key, (sec, kind) in t.ops.items()
+                         if key.startswith(prefix)
+                         and k["ops"](key[len(prefix):], kind))
+            least += runs * max(k["flops"] / self.peak["bf16_flops_per_s"],
+                                k["bytes"] / self.peak["hbm_bytes_per_s"])
+        return 100.0 * least / spent if spent else None
 
 
 # ---------------------------------------------------------------------------
@@ -235,13 +297,13 @@ def build_handles(cfg: dict, seed: int, build: Callable = published_handle):
     return handles, seeds, time.perf_counter() - t0
 
 
-def make_engine(cfg: dict, handles: dict, seed: int):
+def make_engine(cfg: dict, handles: dict, seed: int, obs=None):
     """A ServingEngine on the configuration's slices with every handle
     registered (warmed) at its stream's prompt shape."""
     import numpy as np
     from repro.serving import ServingEngine, VirtualAccelerator
     engine = ServingEngine([VirtualAccelerator(**s) for s in cfg["slices"]],
-                           seed=seed)
+                           seed=seed, obs=obs)
     t0 = time.perf_counter()
     for st in cfg["streams"]:
         engine.register(handles[st["name"]],
@@ -295,12 +357,12 @@ def take_kept(queue) -> dict:
 
 def readings(cfg: dict, kept: dict, seeds: dict, control: bool = False
              ) -> dict:
-    """Each stream's logits against the reference's: ``<stream>.<number>``
-    -> value. With ``control`` the reference's low-precision control is
-    read too, on the same tokens (``<stream>.control.<number>``)."""
+    """Each stream's logits against its model's reference
+    (``model_module``): ``<stream>.<number>`` -> value. With ``control``
+    the reference's low-precision control is read too, on the same tokens
+    (``<stream>.control.<number>``)."""
     import jax
     import numpy as np
-    from chipbench import reference
     out = {}
     for st in cfg["streams"]:
         name = st["name"]
@@ -308,13 +370,14 @@ def readings(cfg: dict, kept: dict, seeds: dict, control: bool = False
         if toks is None:
             continue
         sizes = cfg["models"][st["model"]]["sizes"]
+        forward = model_module(st["model"]).forward
         with jax.default_matmul_precision("highest"):
-            ref = np.asarray(reference.forward(sizes, seeds[name], toks))
+            ref = np.asarray(forward(sizes, seeds[name], toks))
             for k, v in logit_numbers(got, ref).items():
                 out[f"{name}.{k}"] = v
             if control:
-                ctl = np.asarray(reference.forward(sizes, seeds[name], toks,
-                                                   control=True))
+                ctl = np.asarray(forward(sizes, seeds[name], toks,
+                                         control=True))
                 for k, v in logit_numbers(ctl, ref).items():
                     out[f"{name}.control.{k}"] = v
     return out
@@ -339,15 +402,31 @@ def compare(cfg: dict, kept: dict, seeds: dict) -> dict:
     return {"uncompared": [missing, 0], **checks}
 
 
+def stream_models(cfg: dict) -> dict:
+    """``Run``'s per-stream fields: each stream's model module, sizes and
+    prompt length, and its forward's FLOPs and bytes from the module."""
+    streams = cfg["streams"]
+    modules = {st["name"]: model_module(st["model"]) for st in streams}
+    sizes = {st["name"]: cfg["models"][st["model"]]["sizes"]
+             for st in streams}
+    prompts = {st["name"]: st["prompt"] for st in streams}
+    return {"modules": modules, "sizes": sizes, "prompts": prompts,
+            "flops": {k: m.forward_flops(sizes[k], prompts[k])
+                      for k, m in modules.items()},
+            "bytes": {k: m.forward_bytes(sizes[k], prompts[k])
+                      for k, m in modules.items()}}
+
+
 def run_cell(cell: str, work: dict, cfg: dict, bench: dict, *, seed: int,
              seconds: float, traced: bool, peak: dict, devices: list,
              build: Callable = published_handle, read_control: bool = False
              ) -> dict:
     import jax
-    from chipbench import flops, trace
+    from chipbench import engine_trace, trace
 
     handles, seeds, build_s = build_handles(cfg, seed, build)
-    engine, warmup_s = make_engine(cfg, handles, seed)
+    engine, warmup_s = make_engine(
+        cfg, handles, seed, obs=engine_trace.OBS if traced else None)
     if traced:
         for name, h in handles.items():
             h.fn = _annotated(h.fn, f"dispatch:{name}")
@@ -367,8 +446,14 @@ def run_cell(cell: str, work: dict, cfg: dict, bench: dict, *, seed: int,
     close = queue.finish()
     compiles = _COMPILES[0] - compiles
 
-    summary = None
+    summary = spans = by_span = None
     queue_until = seconds
+    if traced:
+        records = engine.obs.tracer.records
+        spans = {"records": records,
+                 "counters": engine.obs.metrics.snapshot()}
+        by_span = {"idle": None, "tie": None,
+                   "stalls": engine_trace.stalls(records)}
     if traced and tstate["on"]:
         jax.profiler.stop_trace()
         queue_until = tstate["at"]
@@ -378,6 +463,7 @@ def run_cell(cell: str, work: dict, cfg: dict, bench: dict, *, seed: int,
                        for f in queue.frames]
         summary = trace.reduce(path, poll_times=polls,
                                outstanding=outstanding)
+        by_span.update(engine_trace.idle_by_span(summary, records))
         shutil.rmtree(trace_dir, ignore_errors=True)
     mem_peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
                    for d in devices)
@@ -397,17 +483,10 @@ def run_cell(cell: str, work: dict, cfg: dict, bench: dict, *, seed: int,
         checks.update(compare(cfg, kept, seeds))
     correct = all(lim is not None and v <= lim for v, lim in checks.values())
 
-    streams = cfg["streams"]
     run = Run(cell=cell, seconds=seconds, frames=queue.frames,
               setup_s=setup_s, build_s=build_s, warmup_s=warmup_s,
               report=report, queue_until=queue_until, peak=peak,
-              trace=summary,
-              flops={st["name"]: flops.forward_flops(
-                  cfg["models"][st["model"]]["sizes"], st["prompt"])
-                  for st in streams},
-              bytes={st["name"]: flops.forward_bytes(
-                  cfg["models"][st["model"]]["sizes"], st["prompt"])
-                  for st in streams})
+              trace=summary, spans=spans, **stream_models(cfg))
     metrics = {}
     for m in metrics_for(bench, cell, traced):
         value = reader(m["name"])(run)
@@ -426,6 +505,8 @@ def run_cell(cell: str, work: dict, cfg: dict, bench: dict, *, seed: int,
         out["breakdown"] = {
             "device_ops": summary.top_ops[:10],
             "idle_gaps": [[k, v] for k, v in summary.idle_by.items()][:10]}
+    if by_span is not None:
+        out["spans"] = by_span
     out["window_compiles"] = compiles
     missed = [0] * math.ceil(seconds)     # frames that missed, by due second
     for f in run.judged(seconds):

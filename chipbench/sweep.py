@@ -7,9 +7,10 @@
 The cell gives the configuration and the arrival process; its own session
 count is ignored. The models are built and warmed once; each step runs a
 fresh ``ServingEngine`` window with that many sessions and prints one JSON
-line: offered and good frames per second, the share of deadlines met, the
-latency tail, drops, and the backlog (frames due and unfinished) averaged
-over the second quarter and over the last quarter of the window.
+line: offered, good and completed frames per second, the share of
+deadlines met, the latency tail, drops, and the backlog (frames due and
+unfinished) averaged over the second quarter and over the last quarter
+of the window.
 
 The knee is the highest session count at which at least 99% of the frames
 due meet their deadline and the backlog of the last quarter is no more
@@ -70,6 +71,7 @@ def main() -> int:
         row = {"sessions": n,
                "offered_fps": sum(1 for f in queue.frames if f.due < s) / s,
                "goodput_fps": R.reader("goodput_fps")(run),
+               "completed_fps": R.reader("completed_fps")(run),
                "deadline_met_pct": R.reader("deadline_met_pct")(run),
                "latency_p95_ms": R.reader("frame_latency_p95_ms")(run),
                "admit_lag_p95_ms": R.reader("admit_lag_p95_ms")(run),

@@ -25,7 +25,7 @@ sys.path[:0] = [ROOT, os.path.join(ROOT, "src")]
 
 import jax  # noqa: E402
 
-from chipbench import engine_trace, run  # noqa: E402
+from chipbench import engine_trace, run, trace  # noqa: E402
 
 SECONDS = 0.3
 SESSIONS = 2
@@ -69,7 +69,8 @@ def main() -> int:
         f.write(data.replace(here, b"<" * (len(here) - 1) + b"/"))
     shutil.rmtree(tmp)
     engine.obs.tracer.dump_jsonl(os.path.join(out, "engine.spans.jsonl"))
-    got = engine_trace.idle_by_span(xplane, engine.obs.tracer.records)
+    got = engine_trace.idle_by_span(trace.reduce(xplane),
+                                    engine.obs.tracer.records)
     print(json.dumps({**got, "bytes": os.path.getsize(xplane),
                       "records": len(engine.obs.tracer.records)}))
     return 0

@@ -10,6 +10,9 @@ from chipbench import engine_trace, run, trace
 from repro.obs import load_jsonl
 
 MS = 1e-3
+#: the per-layer metrics that read the engine's spans
+SPAN_METRICS = ("decide_us_per_dispatch", "hold_pct", "launch_ms_p50",
+                "queue_wait_p95_ms", "stall_ms_per_s")
 
 
 def rec(sid, kind, t0, t1, **attrs):
@@ -53,6 +56,7 @@ def timeline():
 @pytest.mark.parametrize("name,want", [
     ("hold_pct", 100 * 0.098),
     ("decide_us_per_dispatch", 1e6 * (0.001 + 0.0002)),
+    ("decide_us_per_dispatch.overload", 1e6 * (0.001 + 0.0002)),
     ("queue_wait_p95_ms", 100.4),
     ("launch_ms_p50", 0.2 + 0.6),
     ("stall_ms_per_s", 389.0),
@@ -61,7 +65,8 @@ def test_reader_by_hand(name, want):
     assert run.reader(name)(timeline()) == pytest.approx(want, rel=1e-9)
 
 
-@pytest.mark.parametrize("name", sorted(engine_trace.METRICS))
+@pytest.mark.parametrize("name", SPAN_METRICS
+                         + ("decide_us_per_dispatch.overload",))
 def test_reader_without_spans(name):
     """A run whose engine took no ``obs`` (a parent commit) reads None."""
     parent = SimpleNamespace(queue_until=1.0)
@@ -144,23 +149,22 @@ def recorded(tmp_path_factory):
     with gzip.open(os.path.join(DATA, "engine.xplane.pb.gz"), "rb") as f:
         path.write_bytes(f.read())
     recs = load_jsonl(os.path.join(DATA, "engine.spans.jsonl"))
-    return str(path), recs, engine_trace.idle_by_span(str(path), recs)
+    summary = trace.reduce(str(path))
+    return summary, recs, engine_trace.idle_by_span(summary, recs)
 
 
 def test_idle_by_span_sums_to_the_window_idle(recorded):
-    path, _, got = recorded
-    summary = trace.reduce(path)
-    assert got["window_s"] == pytest.approx(summary.window_s, rel=1e-12)
+    summary, _, got = recorded
     idle = summary.window_s - summary.busy_s
-    assert got["idle_s"] == pytest.approx(idle, rel=1e-9)
+    assert sum(b - a for a, b in summary.gaps) * trace.NS == pytest.approx(
+        idle, rel=1e-9)
     assert sum(got["idle"].values()) == pytest.approx(idle, rel=1e-9)
 
 
 def test_every_engine_annotation_pairs_by_sid(recorded):
-    path, recs, got = recorded
-    host, _ = engine_trace._load(path)
+    summary, recs, got = recorded
     kinds = {r["sid"]: r["kind"] for r in recs}
-    ours = [(n, sid) for n, _, _, sid in host if n.startswith("serve.")]
+    ours = [(n, sid) for n, _, _, sid in summary.serve]
     assert ours and all(kinds[sid] == n for n, sid in ours)
     assert got["tie"]["n"] == len(ours)
     # the annotation opens within microseconds of the span
@@ -168,9 +172,9 @@ def test_every_engine_annotation_pairs_by_sid(recorded):
 
 
 def test_idle_lies_in_engine_spans(recorded):
-    _, _, got = recorded
-    idle = got["idle"]
-    assert idle["unspanned"] < 0.05 * got["idle_s"]
-    assert idle["serve.idle:no_frame"] > 0.3 * got["idle_s"]
+    summary, _, got = recorded
+    idle, idle_s = got["idle"], summary.window_s - summary.busy_s
+    assert idle["unspanned"] < 0.05 * idle_s
+    assert idle["serve.idle:no_frame"] > 0.3 * idle_s
     for kind in ("serve.h2d", "serve.launch", "serve.wait", "serve.poll"):
         assert idle[kind] > 0, kind

@@ -1,11 +1,12 @@
 """FLOPs, bytes and peaks of the yardstick, on the CPU."""
 import json
 import os
+from types import SimpleNamespace
 
 import jax
 import pytest
 
-from chipbench import flops, run
+from chipbench import flops, run, trace
 
 CONFIGS = os.path.join(os.path.dirname(os.path.dirname(__file__)), "configs")
 
@@ -84,3 +85,37 @@ def test_peaks():
     assert v5e["hbm_bytes_per_s"] == 819e9
     with pytest.raises(run.NoChip):
         run.peaks_for("TPU v4")
+
+
+def test_kernel_roofline():
+    """A kernel named by a model module: its forwards' least time over the
+    self time of the operations its ``ops`` picks out, in every stream
+    whose module names it."""
+    def kernels(sizes, s):
+        assert sizes == {"d": s}
+        return {"ssd": {"flops": 2e9, "bytes": 1e6,
+                        "ops": lambda name, kind:
+                        kind == "custom-call:tpu_custom_call"}}
+
+    named = SimpleNamespace(kernels=kernels)
+    summary = trace.Summary(
+        window_s=1.0, busy_s=0.5, offset_s=0.0,
+        runs={"kws": [4, 0.01], "ctx": [2, 0.01], "translate": [3, 0.1]},
+        ops={"kws:ssd.1": [2e-4, "custom-call:tpu_custom_call"],
+             "kws:fusion.1": [5e-3, "fusion"],
+             "ctx:ssd.1": [4e-4, "custom-call:tpu_custom_call"],
+             "translate:ssd.1": [9e-4, "custom-call:tpu_custom_call"]})
+    got = run.Run(cell="c", seconds=1.0, frames=[], setup_s=0.0,
+                  build_s=0.0, warmup_s=0.0, report=None, queue_until=1.0,
+                  peak=run.peaks_for("TPU v5 lite"), trace=summary,
+                  modules={"kws": named, "ctx": named,
+                           "translate": SimpleNamespace()},
+                  sizes={"kws": {"d": 32}, "ctx": {"d": 64},
+                         "translate": {}},
+                  prompts={"kws": 32, "ctx": 64, "translate": 64})
+    least = 6 * max(2e9 / 197e12, 1e6 / 819e9)
+    assert got.kernel_roofline("ssd") == pytest.approx(
+        100 * least / 6e-4, rel=1e-12)
+    assert got.kernel_roofline("flash") is None
+    got.trace = None
+    assert got.kernel_roofline("ssd") is None

@@ -8,7 +8,9 @@ What the reduction reads:
 * the host plane (``/host:CPU``): the harness's own annotations,
   ``dispatch:<stream>`` around each handle call (it only enqueues),
   ``poll`` and ``trigger`` around the queue's two calls, and the
-  runtime's ``PJRT_LoadedExecutable_Execute`` events.
+  runtime's ``PJRT_LoadedExecutable_Execute`` events;
+  the engine's ``serve.*`` annotations, each with its span's ``sid``, are
+  kept for ``engine_trace.idle_by_span``.
 
 The device clock of a trace is offset from the host's by a millisecond
 or two. A program cannot start before the host asked for it, so the
@@ -27,9 +29,16 @@ copy, its 1 ms sleep).
 
 Program runs are given to streams by pairing them in order with the
 ``dispatch:`` annotations, again from the end.
+
+Every operation's self time (its duration less its direct children's)
+is kept in ``ops`` under ``<stream>:<op>``, beside the op's kind read
+from its HLO text: the opcode, and for a custom call
+``custom-call:<custom_call_target>`` (a Pallas kernel on a TPU is
+``custom-call:tpu_custom_call``). ``top_ops`` is the head of that table.
 """
 from __future__ import annotations
 
+import re
 from collections import defaultdict
 from dataclasses import dataclass, field
 
@@ -44,6 +53,10 @@ class Summary:
     runs: dict = field(default_factory=dict)    # stream -> [n, device_s]
     idle_by: dict = field(default_factory=dict)  # host activity -> s
     top_ops: list = field(default_factory=list)  # [name, self seconds]
+    ops: dict = field(default_factory=dict)      # name -> [self s, kind]
+    gaps: list = field(default_factory=list)     # device 0 idle, host ns
+    serve: list = field(default_factory=list)    # engine annotations:
+    #                                  (name, start ns, end ns, sid)
 
 
 def _union(intervals):
@@ -102,9 +115,27 @@ def _short(hlo: str) -> str:
     return hlo.split(" ", 1)[0].lstrip("%")
 
 
-def _top_ops(ops, mods, owner, self_by) -> None:
+_OPCODE = re.compile(r" ([a-z][a-z0-9-]*)\(")
+_TARGET = re.compile(r'custom_call_target="([^"]*)"')
+
+
+def op_kind(hlo: str) -> str:
+    """The opcode of one HLO instruction's text (``%name = <shape>
+    opcode(operands), ...``), with ``:<custom_call_target>`` for a custom
+    call; ``?`` where the text holds no instruction."""
+    m = _OPCODE.search(hlo, hlo.find("=") + 1)
+    if m is None:
+        return "?"
+    if m.group(1) != "custom-call":
+        return m.group(1)
+    target = _TARGET.search(hlo, m.end())
+    return f"custom-call:{target.group(1) if target else '?'}"
+
+
+def _self_times(ops, mods, owner, self_by, kinds) -> None:
     """Add each operation's self time (its duration less its direct
-    children's) to ``self_by``, under ``<stream>:<operation>``."""
+    children's) to ``self_by``, under ``<stream>:<operation>``, and its
+    kind to ``kinds``."""
     import bisect
     starts = [m[1] for m in mods]
     stack: list = []               # [end, label, self]
@@ -120,7 +151,10 @@ def _top_ops(ops, mods, owner, self_by) -> None:
         i = bisect.bisect_right(starts, a) - 1
         stream = owner.get(mods[i][1], "?") if i >= 0 and a < mods[i][2] \
             else "?"
-        stack.append([b, f"{stream}:{_short(name)}", b - a])
+        label = f"{stream}:{_short(name)}"
+        if label not in kinds:
+            kinds[label] = op_kind(name)
+        stack.append([b, label, b - a])
     while stack:
         close(stack.pop())
 
@@ -139,7 +173,7 @@ def reduce(path: str, poll_times=None, outstanding=None,
     from jax.profiler import ProfileData
 
     pd = ProfileData.from_file(path)
-    host, devices = [], []
+    host, devices, serve = [], [], []
     for plane in pd.planes:
         if plane.name.startswith("/device:TPU:"):
             lines = {ln.name: _events(ln) for ln in plane.lines}
@@ -148,6 +182,10 @@ def reduce(path: str, poll_times=None, outstanding=None,
         elif plane.name == "/host:CPU":
             for ln in plane.lines:
                 host.extend(_events(ln))
+                serve.extend((e.name, e.start_ns,
+                              e.start_ns + e.duration_ns,
+                              dict(e.stats).get("sid"))
+                             for e in ln.events if e.name.startswith("serve."))
     if not devices:
         raise ValueError(f"{path}: no TPU device plane")
 
@@ -174,6 +212,7 @@ def reduce(path: str, poll_times=None, outstanding=None,
     runs: dict = defaultdict(lambda: [0, 0.0])
     idle_by: dict = defaultdict(float)
     self_by: dict = defaultdict(float)
+    kinds: dict = {}
     for di, (modules, ops) in enumerate(devices):
         mods = sorted(((n, a + offset, b + offset) for n, a, b in modules),
                       key=lambda e: e[1])
@@ -210,12 +249,13 @@ def reduce(path: str, poll_times=None, outstanding=None,
                                        - busy_host) * NS
             else:
                 idle_by["engine_loop"] += sum(b - a for a, b in rest) * NS
-            _top_ops([o for o in shifted if o[1] >= w0 and o[2] <= w1],
-                     mods, owner, self_by)
+            _self_times([o for o in shifted if o[1] >= w0 and o[2] <= w1],
+                        mods, owner, self_by, kinds)
     n_dev = len(devices)
+    ops = sorted(self_by.items(), key=lambda kv: -kv[1])
     return Summary(
         window_s=(w1 - w0) * NS, busy_s=busy_total * NS / n_dev,
         offset_s=offset * NS, runs={k: [v[0], v[1]] for k, v in runs.items()},
         idle_by=dict(sorted(idle_by.items(), key=lambda kv: -kv[1])),
-        top_ops=sorted(([k, v] for k, v in self_by.items()),
-                       key=lambda kv: -kv[1])[:top])
+        top_ops=[[k, v] for k, v in ops[:top]],
+        ops={k: [v, kinds[k]] for k, v in ops}, gaps=gaps, serve=serve)
